@@ -34,6 +34,7 @@ import argparse
 import re
 import sys
 import time
+import traceback
 
 
 def main() -> None:
@@ -67,19 +68,24 @@ def main() -> None:
         ("modeled_cost", modeled_cost.run),
     ]
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in benches:
         if args.only and not re.search(args.only, name):
             continue
         t0 = time.time()
         try:
             rows = fn()
-        except Exception as e:  # keep the harness robust
+        except Exception as e:  # run the remaining benches, fail at exit
+            traceback.print_exc()
             print(f"{name},0.0,ERROR:{type(e).__name__}:{e}",
                   file=sys.stdout)
+            failed.append(name)
             continue
         for rname, us, derived in rows:
             print(f"{rname},{us:.1f},{derived}")
         print(f"# {name} done in {time.time()-t0:.0f}s", file=sys.stderr)
+    if failed:
+        sys.exit(f"benches failed: {', '.join(failed)}")
 
 
 if __name__ == '__main__':

@@ -178,6 +178,9 @@ def _sharded_row(steps, seed=0):
     """Spawn the 4-device worker (the main process keeps one device)."""
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    # a forced-host-device harness: it must never reach for a chip the
+    # parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count=4").strip()
     env["PYTHONPATH"] = os.path.join(root, "src") + (

@@ -52,7 +52,8 @@ def run_one(arch, shape, multi_pod, obj="lm", red="fastclip", timeout=1500):
            "--out", out_json]
     if multi_pod:
         cmd.append("--multi-pod")
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+               JAX_PLATFORMS="cpu")  # dryrun forces host devices
     t0 = time.time()
     try:
         p = subprocess.run(cmd, capture_output=True, text=True,

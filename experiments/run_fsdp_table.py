@@ -13,7 +13,8 @@ def run(arch, mp=False):
     cmd = [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
            "--shape", "train_4k", "--sharding", "fsdp", "--out", out]
     if mp: cmd.append("--multi-pod")
-    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+               JAX_PLATFORMS="cpu")  # dryrun forces host devices
     t0=time.time()
     p = subprocess.run(cmd, capture_output=True, text=True, timeout=900, env=env)
     if p.returncode: open(out+".err","w").write(p.stderr[-5000:])

@@ -56,6 +56,10 @@ Gradient math (Appendix A, both sides, per-row taus, log-domain weights):
                             + sum_i A1[i,p] e1_i - (sum_j A1[p,j]) e1_p ]
 Every term for local p needs only local rows of A, the gathered features
 (forward residuals) and gathered scalars.
+
+The repo's ``jax.shard_map`` calls pass ``check_vma=False``: the loss
+islands mix replicated scalars and sharded rows, which the
+varying-manual-axes check rejects.
 """
 from __future__ import annotations
 
@@ -67,18 +71,6 @@ import jax.numpy as jnp
 from repro.core import losses as LS
 
 sg = jax.lax.stop_gradient
-
-
-def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions, with the replication /
-    varying-manual-axes check disabled (our loss islands mix replicated
-    scalars and sharded rows, which the checker rejects)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
 
 
 def gather_axes(x, axes):
@@ -108,26 +100,18 @@ def _psum(x, axes):
     return jax.lax.psum(x, axes)
 
 
-def axis_size(ax):
-    """``jax.lax.axis_size`` across jax versions (public compat shim,
-    usable from any shard_map body — see also ``shard_map`` above)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(ax)
-    return jax.lax.psum(1, ax)   # folds to the static size
-
-
 def _global_index(axes):
     """Flattened shard index over possibly-multiple mesh axes."""
     idx = 0
     for ax in axes:
-        idx = idx * axis_size(ax) + jax.lax.axis_index(ax)
+        idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return idx
 
 
 def _axis_prod(axes):
     out = 1
     for ax in axes:
-        out *= axis_size(ax)
+        out *= jax.lax.axis_size(ax)
     return out
 
 

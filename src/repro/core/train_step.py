@@ -96,8 +96,9 @@ def make_loss_core(fc: FC.FastCLIPConfig, mesh_axes: Optional[Sequence[str]],
                     pspec if tau_is_arr else P())
         out_specs = (P(), pspec, pspec, pspec, pspec,
                      (pspec,) * 6, pspec)
-        fn = D.shard_map(inner, mesh=_current_mesh(),
-                         in_specs=in_specs, out_specs=out_specs)
+        fn = jax.shard_map(inner, mesh=_current_mesh(),
+                           in_specs=in_specs, out_specs=out_specs,
+                           check_vma=False)
         loss, lu1_new, lu2_new, lu1r, lu2r, stats, sat = fn(
             e1n, e2n, lu1, lu2, idx, tau1, tau2)
         aux = {"u1_new": sg(lu1_new), "u2_new": sg(lu2_new),
@@ -276,10 +277,10 @@ def make_train_step(tc: TrainStepConfig):
                     from jax.sharding import PartitionSpec as P
                     axes = tuple(tc.mesh_axes)
                     f = D.make_mbcl_loss(axes)
-                    loss = D.shard_map(
+                    loss = jax.shard_map(
                         f, mesh=_current_mesh(),
                         in_specs=(P(axes), P(axes), P()),
-                        out_specs=P())(e1n, e2n, tau_diff)
+                        out_specs=P(), check_vma=False)(e1n, e2n, tau_diff)
                 return loss, {"e1n": sg(e1n), "e2n": sg(e2n)}
             t1 = fcs["tau1"] if fc.individual_tau else sg(tau_diff)
             t2 = fcs["tau2"] if fc.individual_tau else sg(tau_diff)
@@ -589,9 +590,9 @@ def make_fsdp_train_step(tc: TrainStepConfig, param_dims=None):
 
     def train_step(state, batch, idx):
         b_specs = SS.batch_specs(batch)
-        fn = D.shard_map(step_local, mesh=mesh,
-                         in_specs=(state_specs, b_specs, P(axes)),
-                         out_specs=(state_specs, P()))
+        fn = jax.shard_map(step_local, mesh=mesh,
+                           in_specs=(state_specs, b_specs, P(axes)),
+                           out_specs=(state_specs, P()), check_vma=False)
         return fn(state, batch, idx)
 
     return train_step

@@ -115,8 +115,8 @@ def sharded_retrieval_topk(mesh, axes, e1n, e2n, k, *, chunk=CHUNK,
         s2, i2 = topk(e2l, e1l)
         return s1, i1, s2, i2
 
-    fn = D.shard_map(inner, mesh=mesh, in_specs=(pspec, pspec),
-                     out_specs=(pspec,) * 4)
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=(pspec, pspec),
+                       out_specs=(pspec,) * 4, check_vma=False)
     s1, i1, s2, i2 = fn(e1n, e2n)
     return (s1, i1), (s2, i2)
 

@@ -33,6 +33,18 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def mxu_dot(a, b, dimension_numbers):
+    """In-kernel ``dot_general`` accumulating in f32.  bf16 operands take
+    DEFAULT precision: their products are exact in the f32 accumulator,
+    and Mosaic refuses a bf16 dot at the HIGHEST precision that a
+    ``jax.default_matmul_precision("highest")`` context would request.
+    f32 operands keep the context's precision."""
+    precision = jax.lax.Precision.DEFAULT if a.dtype == jnp.bfloat16 \
+        else None
+    return jax.lax.dot_general(a, b, dimension_numbers, precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                   scale, causal, window, n_valid_k, n_k_blocks):
     qi = pl.program_id(1)
@@ -46,8 +58,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     q = q_ref[0]                       # (BQ, hd)
     k = k_ref[0]                       # (BK, hd)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+    s = mxu_dot(q, k, (((1,), (1,)), ((), ()))) * scale
     q_pos = qi * BQ + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 0)
     k_pos = ki * BK + jax.lax.broadcasted_iota(jnp.int32, (BQ, BK), 1)
     mask = k_pos < n_valid_k
@@ -63,10 +74,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     alpha = jnp.exp(m_prev - m_new)
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
     acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                    + jax.lax.dot_general(
-                        p.astype(v_ref.dtype), v_ref[0],
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32))
+                    + mxu_dot(p.astype(v_ref.dtype), v_ref[0],
+                              (((1,), (0,)), ((), ()))))
     m_ref[...] = m_new
 
     @pl.when(ki == n_k_blocks - 1)

@@ -31,6 +31,13 @@ Row indices are passed in as an int32 vector (padded with -1) rather than
 derived from the grid position because ``row_offset`` is a traced value
 inside shard_map (it comes from ``axis_index``).
 
+Per-row vectors (row ids, s_ii, taus, log-weights and the row-stat
+outputs) travel as (n, 1) columns in (BR, 1) blocks and per-column
+vectors as (1, n) rows in (1, BC) blocks: the TPU compiler refuses 1-D
+blocks smaller than the whole array (the XLA tiling of an s32[512]
+operand does not match Mosaic's), and the 2-D forms arrive already
+broadcast against the (BR, BC) tile.
+
 Tiles are 128-aligned for the MXU; inputs may be bf16 (blocks stay bf16 in
 VMEM — half the feature traffic) with all accumulation in f32
 (``preferred_element_type``).  For wide embeddings both kernels block the
@@ -65,6 +72,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.losses import EXP_CLAMP, MASK_NEG
 from repro.kernels import autotune
+from repro.kernels.flash_attention import mxu_dot
 
 # Shipped tile defaults.  Call sites that leave ``br``/``bc``/``d_block``
 # unset consult the autotune table (repro.kernels.autotune, produced by
@@ -110,6 +118,16 @@ def _pad_vec(x, n, m, value=0.0):
     return _pad_rows(jnp.broadcast_to(x, (n,)).astype(jnp.float32), m, value)
 
 
+def _row_vec(x, n, m, value=0.0):
+    """``_pad_vec`` as an (n_pad, 1) column, blocked (BR, 1)."""
+    return _pad_vec(x, n, m, value)[:, None]
+
+
+def _col_vec(x, n, m, value=0.0):
+    """``_pad_vec`` as a (1, n_pad) row, blocked (1, BC)."""
+    return _pad_vec(x, n, m, value)[None, :]
+
+
 # ---------------------------------------------------------------------------
 # Forward stats kernel (online softmax over column tiles)
 # ---------------------------------------------------------------------------
@@ -136,32 +154,32 @@ def _stats_kernel(rid_ref, e1r_ref, e2r_ref, e1c_ref, e2c_ref, sdr_ref,
         s2_acc[...] = jnp.zeros_like(s2_acc)
 
     # partial similarity over this d chunk; f32 accumulation in scratch
-    s1_acc[...] += jax.lax.dot_general(
-        e1r_ref[...], e2c_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    s2_acc[...] += jax.lax.dot_general(
-        e2r_ref[...], e1c_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    s1_acc[...] += mxu_dot(
+        e1r_ref[...], e2c_ref[...], (((1,), (1,)), ((), ())))
+    s2_acc[...] += mxu_dot(
+        e2r_ref[...], e1c_ref[...], (((1,), (1,)), ((), ())))
 
     @pl.when(k == n_d_blocks - 1)
     def _online_update():
-        sd = sdr_ref[...].astype(jnp.float32)            # (br,)
-        rows = rid_ref[...][:, None]                     # (br, 1) global
+        sd = sdr_ref[...].astype(jnp.float32)            # (br, 1)
+        rows = rid_ref[...]                              # (br, 1) global
         cols = c * bc + jax.lax.broadcasted_iota(jnp.int32, (br, bc), 1)
         mask = (rows != cols) & (cols < n_cols) & (rows >= 0)
         for s, t_ref, g_ref, dg_ref, m_ref in (
                 (s1_acc[...], t1_ref, g1_ref, dg1_ref, m1_ref),
                 (s2_acc[...], t2_ref, g2_ref, dg2_ref, m2_ref)):
             t = t_ref[...].astype(jnp.float32)
-            z = jnp.where(mask, (s - sd[:, None]) / t[:, None], MASK_NEG)
-            m_new = jnp.maximum(m_ref[...], jnp.max(z, axis=1))
+            z = jnp.where(mask, (s - sd) / t, MASK_NEG)
+            m_new = jnp.maximum(m_ref[...],
+                                jnp.max(z, axis=1, keepdims=True))
             # MASK_NEG - MASK_NEG == 0 (finite sentinel), so alpha == 1 on
             # still-empty rows instead of nan
             alpha = jnp.exp(m_ref[...] - m_new)
-            p = jnp.where(mask, jnp.exp(z - m_new[:, None]), 0.0)
-            g_ref[...] = g_ref[...] * alpha + jnp.sum(p, axis=1)
+            p = jnp.where(mask, jnp.exp(z - m_new), 0.0)
+            g_ref[...] = g_ref[...] * alpha + jnp.sum(p, axis=1,
+                                                      keepdims=True)
             dg_ref[...] = (dg_ref[...] * alpha
-                           + jnp.sum(p * -(s - sd[:, None]), axis=1)
+                           + jnp.sum(p * -(s - sd), axis=1, keepdims=True)
                            / (t ** 2))
             m_ref[...] = m_new
 
@@ -191,21 +209,21 @@ def gcl_pair_stats(e1, e2, tau1, tau2, *, e1_all=None, e2_all=None,
         d_block = d if d <= D_BLOCK_MAX else D_BLOCK_MAX
     sd = jnp.sum(e1.astype(jnp.float32) * e2.astype(jnp.float32), axis=-1)
     rid = row_offset + jnp.arange(b, dtype=jnp.int32)
-    ridp = _pad_rows(rid, br, value=-1)
+    ridp = _pad_rows(rid, br, value=-1)[:, None]
     e1p = _pad_cols(_pad_rows(e1, br), d_block)
     e2p = _pad_cols(_pad_rows(e2, br), d_block)
     e1cp = _pad_cols(_pad_rows(e1_all, bc), d_block)
     e2cp = _pad_cols(_pad_rows(e2_all, bc), d_block)
-    sdp = _pad_vec(sd, b, br)
-    t1p = _pad_vec(tau1, b, br, 1.0)
-    t2p = _pad_vec(tau2, b, br, 1.0)
+    sdp = _row_vec(sd, b, br)
+    t1p = _row_vec(tau1, b, br, 1.0)
+    t2p = _row_vec(tau2, b, br, 1.0)
     bp, Bp, dp = e1p.shape[0], e1cp.shape[0], e1p.shape[1]
     nk = dp // d_block
     grid = (bp // br, Bp // bc, nk)
 
     row_spec = pl.BlockSpec((br, d_block), lambda r, c, k: (r, k))
     col_spec = pl.BlockSpec((bc, d_block), lambda r, c, k: (c, k))
-    vec_row = pl.BlockSpec((br,), lambda r, c, k: (r,))
+    vec_row = pl.BlockSpec((br, 1), lambda r, c, k: (r, 0))
 
     out = pl.pallas_call(
         functools.partial(_stats_kernel, n_cols=B, n_d_blocks=nk,
@@ -214,12 +232,12 @@ def gcl_pair_stats(e1, e2, tau1, tau2, *, e1_all=None, e2_all=None,
         in_specs=[vec_row, row_spec, row_spec, col_spec, col_spec,
                   vec_row, vec_row, vec_row],
         out_specs=[vec_row] * 6,
-        out_shape=[jax.ShapeDtypeStruct((bp,), jnp.float32)] * 6,
+        out_shape=[jax.ShapeDtypeStruct((bp, 1), jnp.float32)] * 6,
         scratch_shapes=[pltpu.VMEM((br, bc), jnp.float32)] * 2,
         interpret=interpret,
     )(ridp, e1p, e2p, e1cp, e2cp, sdp, t1p, t2p)
     denom = float(max(B - 1, 1))
-    g1, g2, dg1, dg2, m1, m2 = (o[:b] for o in out)
+    g1, g2, dg1, dg2, m1, m2 = (o[:b, 0] for o in out)
     return g1 / denom, g2 / denom, dg1 / denom, dg2 / denom, m1, m2
 
 
@@ -245,40 +263,33 @@ def _grads_kernel(rid_ref, e1r_ref, e2r_ref, e1c_ref, e2c_ref, sdr_ref,
     sdr = sdr_ref[...].astype(jnp.float32)
     sdc = sdc_ref[...].astype(jnp.float32)
 
-    rows = rid_ref[...][:, None]                     # (br, 1) global ids
+    rows = rid_ref[...]                              # (br, 1) global ids
     cols = c * bc + jax.lax.broadcasted_iota(jnp.int32, (br, bc), 1)
     mask = (rows != cols) & (cols < n_cols) & (rows >= 0)
 
-    s1 = jax.lax.dot_general(e1r_ref[...], e2c, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    s2 = jax.lax.dot_general(e2r_ref[...], e1c, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+    s1 = mxu_dot(e1r_ref[...], e2c, (((1,), (1,)), ((), ())))
+    s2 = mxu_dot(e2r_ref[...], e1c, (((1,), (1,)), ((), ())))
 
     def a(z):
         # exp(z + lwt) <= B/gamma by the log-domain weight bound; the
         # EXP_CLAMP min is the shared last-resort guard only
         return jnp.where(mask, jnp.exp(jnp.minimum(z, EXP_CLAMP)), 0.0)
 
-    a1 = a((s1 - sdr[:, None]) / t1r_ref[...][:, None]
-           + lwt1r_ref[...][:, None])
-    a2 = a((s2 - sdr[:, None]) / t2r_ref[...][:, None]
-           + lwt2r_ref[...][:, None])
+    # row vectors are (br, 1), column vectors (1, bc)
+    a1 = a((s1 - sdr) / t1r_ref[...] + lwt1r_ref[...])
+    a2 = a((s2 - sdr) / t2r_ref[...] + lwt2r_ref[...])
     # transpose blocks: m1[p, j] = A1[j, p] over column anchors j
     #   A1[j, p] = exp((e1_j.e2_p - sd_j)/t1_j + lwt1_j); e1_j.e2_p = s2[p, j]
-    m1 = a((s2 - sdc[None, :]) / t1c_ref[...][None, :]
-           + lwt1c_ref[...][None, :])
+    m1 = a((s2 - sdc) / t1c_ref[...] + lwt1c_ref[...])
     #   A2[j, p] = exp((e2_j.e1_p - sd_j)/t2_j + lwt2_j); e2_j.e1_p = s1[p, j]
-    m2 = a((s1 - sdc[None, :]) / t2c_ref[...][None, :]
-           + lwt2c_ref[...][None, :])
+    m2 = a((s1 - sdc) / t2c_ref[...] + lwt2c_ref[...])
 
-    de1_ref[...] += jax.lax.dot_general(
-        (a1 + m2).astype(e2c.dtype), e2c, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    de2_ref[...] += jax.lax.dot_general(
-        (a2 + m1).astype(e1c.dtype), e1c, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    r1_ref[...] += jnp.sum(a1, axis=1)
-    r2_ref[...] += jnp.sum(a2, axis=1)
+    de1_ref[...] += mxu_dot(
+        (a1 + m2).astype(e2c.dtype), e2c, (((1,), (0,)), ((), ())))
+    de2_ref[...] += mxu_dot(
+        (a2 + m1).astype(e1c.dtype), e1c, (((1,), (0,)), ((), ())))
+    r1_ref[...] += jnp.sum(a1, axis=1, keepdims=True)
+    r2_ref[...] += jnp.sum(a2, axis=1, keepdims=True)
 
 
 def _grads_kernel_dblocked(rid_ref, e1r_ref, e2r_ref, e1c_ref, e2c_ref,
@@ -314,12 +325,10 @@ def _grads_kernel_dblocked(rid_ref, e1r_ref, e2r_ref, e1c_ref, e2c_ref,
             s1_acc[...] = jnp.zeros_like(s1_acc)
             s2_acc[...] = jnp.zeros_like(s2_acc)
 
-        s1_acc[...] += jax.lax.dot_general(
-            e1r_ref[...], e2c_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s2_acc[...] += jax.lax.dot_general(
-            e2r_ref[...], e1c_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        s1_acc[...] += mxu_dot(
+            e1r_ref[...], e2c_ref[...], (((1,), (1,)), ((), ())))
+        s2_acc[...] += mxu_dot(
+            e2r_ref[...], e1c_ref[...], (((1,), (1,)), ((), ())))
 
     @pl.when((ph == 1) & (k == 0))
     def _pair_weights():
@@ -327,36 +336,30 @@ def _grads_kernel_dblocked(rid_ref, e1r_ref, e2r_ref, e1c_ref, e2c_ref,
         s2 = s2_acc[...]
         sdr = sdr_ref[...].astype(jnp.float32)
         sdc = sdc_ref[...].astype(jnp.float32)
-        rows = rid_ref[...][:, None]
+        rows = rid_ref[...]
         cols = c * bc + jax.lax.broadcasted_iota(jnp.int32, (br, bc), 1)
         mask = (rows != cols) & (cols < n_cols) & (rows >= 0)
 
         def a(z):
             return jnp.where(mask, jnp.exp(jnp.minimum(z, EXP_CLAMP)), 0.0)
 
-        a1 = a((s1 - sdr[:, None]) / t1r_ref[...][:, None]
-               + lwt1r_ref[...][:, None])
-        a2 = a((s2 - sdr[:, None]) / t2r_ref[...][:, None]
-               + lwt2r_ref[...][:, None])
-        m1 = a((s2 - sdc[None, :]) / t1c_ref[...][None, :]
-               + lwt1c_ref[...][None, :])
-        m2 = a((s1 - sdc[None, :]) / t2c_ref[...][None, :]
-               + lwt2c_ref[...][None, :])
+        a1 = a((s1 - sdr) / t1r_ref[...] + lwt1r_ref[...])
+        a2 = a((s2 - sdr) / t2r_ref[...] + lwt2r_ref[...])
+        m1 = a((s2 - sdc) / t1c_ref[...] + lwt1c_ref[...])
+        m2 = a((s1 - sdc) / t2c_ref[...] + lwt2c_ref[...])
         p1_acc[...] = a1 + m2
         p2_acc[...] = a2 + m1
-        r1_ref[...] += jnp.sum(a1, axis=1)
-        r2_ref[...] += jnp.sum(a2, axis=1)
+        r1_ref[...] += jnp.sum(a1, axis=1, keepdims=True)
+        r2_ref[...] += jnp.sum(a2, axis=1, keepdims=True)
 
     @pl.when(ph == 1)
     def _accum_grads():
         e1c = e1c_ref[...]
         e2c = e2c_ref[...]
-        de1_ref[...] += jax.lax.dot_general(
-            p1_acc[...].astype(e2c.dtype), e2c, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        de2_ref[...] += jax.lax.dot_general(
-            p2_acc[...].astype(e1c.dtype), e1c, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        de1_ref[...] += mxu_dot(
+            p1_acc[...].astype(e2c.dtype), e2c, (((1,), (0,)), ((), ())))
+        de2_ref[...] += mxu_dot(
+            p2_acc[...].astype(e1c.dtype), e1c, (((1,), (0,)), ((), ())))
 
 
 def gcl_pair_grads(e1, e2, lwt1, lwt2, tau1, tau2, *, e1_all=None,
@@ -396,18 +399,18 @@ def gcl_pair_grads(e1, e2, lwt1, lwt2, tau1, tau2, *, e1_all=None,
     if blocked:
         e1p, e2p = _pad_cols(e1p, d_block), _pad_cols(e2p, d_block)
         e1cp, e2cp = _pad_cols(e1cp, d_block), _pad_cols(e2cp, d_block)
-    ridp = _pad_rows(rid, br, value=-1)
-    sdp = _pad_vec(sd, b, br)
-    sdcp = _pad_vec(sd_all, B, bc)
+    ridp = _pad_rows(rid, br, value=-1)[:, None]
+    sdp = _row_vec(sd, b, br)
+    sdcp = _col_vec(sd_all, B, bc)
     # padded rows/cols are masked out via rid/n_cols; MASK_NEG keeps their
     # exponents at -inf rather than trusting the mask alone
-    lw1p = _pad_vec(lwt1, b, br, MASK_NEG)
-    lw2p = _pad_vec(lwt2, b, br, MASK_NEG)
-    lw1cp = _pad_vec(lwt1_all, B, bc, MASK_NEG)
-    lw2cp = _pad_vec(lwt2_all, B, bc, MASK_NEG)
-    t1p, t2p = _pad_vec(tau1, b, br, 1.0), _pad_vec(tau2, b, br, 1.0)
-    t1cp = _pad_vec(tau1_all, B, bc, 1.0)
-    t2cp = _pad_vec(tau2_all, B, bc, 1.0)
+    lw1p = _row_vec(lwt1, b, br, MASK_NEG)
+    lw2p = _row_vec(lwt2, b, br, MASK_NEG)
+    lw1cp = _col_vec(lwt1_all, B, bc, MASK_NEG)
+    lw2cp = _col_vec(lwt2_all, B, bc, MASK_NEG)
+    t1p, t2p = _row_vec(tau1, b, br, 1.0), _row_vec(tau2, b, br, 1.0)
+    t1cp = _col_vec(tau1_all, B, bc, 1.0)
+    t2cp = _col_vec(tau2_all, B, bc, 1.0)
     bp, Bp, dp = e1p.shape[0], e1cp.shape[0], e1p.shape[1]
 
     if blocked:
@@ -415,8 +418,8 @@ def gcl_pair_grads(e1, e2, lwt1, lwt2, tau1, tau2, *, e1_all=None,
         grid = (bp // br, Bp // bc, 2, nk)
         row_spec = pl.BlockSpec((br, d_block), lambda r, c, p, k: (r, k))
         col_spec = pl.BlockSpec((bc, d_block), lambda r, c, p, k: (c, k))
-        vrow = pl.BlockSpec((br,), lambda r, c, p, k: (r,))
-        vcol = pl.BlockSpec((bc,), lambda r, c, p, k: (c,))
+        vrow = pl.BlockSpec((br, 1), lambda r, c, p, k: (r, 0))
+        vcol = pl.BlockSpec((1, bc), lambda r, c, p, k: (0, c))
         de_spec = pl.BlockSpec((br, d_block), lambda r, c, p, k: (r, k))
         kernel = functools.partial(_grads_kernel_dblocked, n_cols=B,
                                    br=br, bc=bc)
@@ -425,8 +428,8 @@ def gcl_pair_grads(e1, e2, lwt1, lwt2, tau1, tau2, *, e1_all=None,
         grid = (bp // br, Bp // bc)
         row_spec = pl.BlockSpec((br, dp), lambda r, c: (r, 0))
         col_spec = pl.BlockSpec((bc, dp), lambda r, c: (c, 0))
-        vrow = pl.BlockSpec((br,), lambda r, c: (r,))
-        vcol = pl.BlockSpec((bc,), lambda r, c: (c,))
+        vrow = pl.BlockSpec((br, 1), lambda r, c: (r, 0))
+        vcol = pl.BlockSpec((1, bc), lambda r, c: (0, c))
         de_spec = pl.BlockSpec((br, dp), lambda r, c: (r, 0))
         kernel = functools.partial(_grads_kernel, n_cols=B, br=br, bc=bc)
         scratch = []
@@ -438,13 +441,13 @@ def gcl_pair_grads(e1, e2, lwt1, lwt2, tau1, tau2, *, e1_all=None,
                   vrow, vrow, vcol, vcol, vrow, vrow, vcol, vcol],
         out_specs=[de_spec] * 2 + [vrow] * 2,
         out_shape=[jax.ShapeDtypeStruct((bp, dp), jnp.float32)] * 2
-        + [jax.ShapeDtypeStruct((bp,), jnp.float32)] * 2,
+        + [jax.ShapeDtypeStruct((bp, 1), jnp.float32)] * 2,
         scratch_shapes=scratch,
         interpret=interpret,
     )(ridp, e1p, e2p, e1cp, e2cp, sdp, sdcp, lw1p, lw2p, lw1cp, lw2cp,
       t1p, t2p, t1cp, t2cp)
     kappa = 1.0 / (B * max(B - 1.0, 1.0))
-    rsum = (r1 + r2)[:b, None]
+    rsum = (r1 + r2)[:b]
     de1 = kappa * (de1[:b, :d] - rsum * e2.astype(jnp.float32))
     de2 = kappa * (de2[:b, :d] - rsum * e1.astype(jnp.float32))
     return de1, de2

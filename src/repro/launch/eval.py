@@ -32,6 +32,7 @@ from repro.configs import get_arch
 from repro.data import ZeroShotEvalDataset
 from repro.eval import engine as EN
 from repro.eval import planted as PL
+from repro.launch.compile_cache import init_compile_cache
 from repro.models import backbones as BB
 from repro.models.precision import POLICIES
 
@@ -76,6 +77,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--json-out", default=None)
     args = ap.parse_args(argv)
+    init_compile_cache()
 
     if args.planted:
         ds = build_eval_dataset(args)

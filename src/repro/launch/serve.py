@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import init_compile_cache
 from repro.models import backbones as BB
 
 
@@ -60,6 +61,7 @@ def main(argv=None):
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    init_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -39,6 +39,7 @@ import numpy as np
 from repro import checkpoint as CK
 from repro.configs import get_arch
 from repro.eval import planted as PL
+from repro.launch.compile_cache import init_compile_cache
 from repro.launch.eval import build_eval_dataset
 from repro.models import backbones as BB
 from repro.models import precision as PR
@@ -180,6 +181,7 @@ def main(argv=None):
     ap.add_argument("--watchdog-timeout", type=float, default=60.0)
     ap.add_argument("--json-out", default=None)
     args = ap.parse_args(argv)
+    init_compile_cache()
 
     # SIGTERM: note it, stop offering; the drain below finishes every
     # admitted request before exit (same contract as launch.train).

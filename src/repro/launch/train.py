@@ -140,6 +140,7 @@ from repro.data import (ContrastiveDataset, DevicePrefetcher, LMDataset,
                         StreamingDataset, StreamingLoader)
 from repro.data import curriculum as CU
 from repro.launch import multiprocess as MP
+from repro.launch.compile_cache import init_compile_cache
 from repro.launch.steps import donated_jit
 from repro.models import backbones as BB
 from repro.models.precision import POLICIES
@@ -181,7 +182,7 @@ def check_resume_metadata(meta, arch: str, version: str) -> None:
                 "directory.")
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="clip-vitb32-cc12m")
     ap.add_argument("--reduced", action="store_true")
@@ -301,7 +302,34 @@ def main(argv=None):
     ap.add_argument("--eval-per-class", type=int, default=8)
     ap.add_argument("--eval-batch", type=int, default=64)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap
+
+
+def train_step_config(args, cfg, steps_per_epoch: int,
+                      sharded: bool) -> TS.TrainStepConfig:
+    """The contrastive train step the flags describe; ``sharded`` selects
+    the (data, fsdp) mesh step over the single-device one."""
+    fc = FC.FastCLIPConfig(
+        version=args.version, n_samples=args.n_samples, rho=args.rho,
+        eps=args.eps, gamma_min=args.gamma_min,
+        tau_init=0.07 if args.version == "v3" else 0.03,
+        lr_tau=2e-4 if args.version == "v3" else 1e-2,
+        steps_per_epoch=steps_per_epoch,
+        gamma_decay_epochs=max(1, args.steps // (2 * steps_per_epoch)))
+    return TS.TrainStepConfig(
+        arch=cfg, fc=fc, optimizer=get_optimizer(args.optimizer),
+        lr_fn=lr_warmup_cosine(args.lr, min(500, args.steps // 10 + 1),
+                               args.steps),
+        wd=args.wd, reduction=args.reduction,
+        loss_impl=args.loss_impl, impl=args.impl,
+        precision=args.precision,
+        mesh_axes=SS.TRAIN_AXES if sharded else None,
+        fsdp=sharded, microbatch=args.microbatch,
+        guard=args.guard or args.rollback_after > 0)
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
 
     multiproc = args.num_processes > 1 or bool(args.coordinator)
     if multiproc:
@@ -317,6 +345,11 @@ def main(argv=None):
     # must happen before any jax device use (backend init is lazy)
     MP.initialize(args.coordinator, args.num_processes, args.process_id,
                   args.local_devices)
+    init_compile_cache()
+    devs = jax.devices()
+    # kernels run compiled only on a TPU; say which backend this run got
+    print(f"devices: platform={devs[0].platform} "
+          f"kind={devs[0].device_kind} count={len(devs)}", flush=True)
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -330,7 +363,6 @@ def main(argv=None):
         args.prefetch = 4 if streaming else 2
     image_sched = CU.parse_schedule(args.image_size_schedule)
     context_sched = CU.parse_schedule(args.context_schedule)
-    guard = args.guard or args.rollback_after > 0
     chaos = RS.parse_chaos(args.chaos, seed=args.seed)
 
     mesh = None
@@ -380,24 +412,8 @@ def main(argv=None):
         def run_step(state, idx, batch):
             return jit_step(state, batch)
     else:
-        fc = FC.FastCLIPConfig(
-            version=args.version, n_samples=args.n_samples, rho=args.rho,
-            eps=args.eps, gamma_min=args.gamma_min,
-            tau_init=0.07 if args.version == "v3" else 0.03,
-            lr_tau=2e-4 if args.version == "v3" else 1e-2,
-            steps_per_epoch=loader.steps_per_epoch,
-            gamma_decay_epochs=max(
-                1, args.steps // (2 * loader.steps_per_epoch)))
-        tc = TS.TrainStepConfig(
-            arch=cfg, fc=fc, optimizer=get_optimizer(args.optimizer),
-            lr_fn=lr_warmup_cosine(args.lr, min(500, args.steps // 10 + 1),
-                                   args.steps),
-            wd=args.wd, reduction=args.reduction,
-            loss_impl=args.loss_impl, impl=args.impl,
-            precision=args.precision,
-            mesh_axes=SS.TRAIN_AXES if mesh is not None else None,
-            fsdp=mesh is not None, microbatch=args.microbatch,
-            guard=guard)
+        tc = train_step_config(args, cfg, loader.steps_per_epoch,
+                               sharded=mesh is not None)
         state = TS.init_train_state(jax.random.PRNGKey(args.seed), tc)
         if mesh is not None:
             from jax.sharding import NamedSharding

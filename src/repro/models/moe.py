@@ -15,7 +15,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
-from repro.core.distributed import axis_size
 from repro.models import layers as L
 
 
@@ -115,7 +114,7 @@ def apply_moe_a2a_local(params, cfg: ArchConfig, x, *, axis="model"):
     in ``params`` carry only the local experts (E_local = E / axis_size).
     Returns (y, aux) like apply_moe."""
     m = cfg.moe
-    K = axis_size(axis)
+    K = jax.lax.axis_size(axis)
     me = jax.lax.axis_index(axis)
     bl, S, d = x.shape
     T = bl * S

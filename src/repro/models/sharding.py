@@ -72,9 +72,9 @@ def apply_moe_sharded(moe_params, cfg, x):
     pspec = {k: (wspec[k] if k in wspec else jax.tree.map(lambda _: P(), v))
              for k, v in moe_params.items()}
     xspec = P(ba, None, None)
-    from repro.core.distributed import shard_map
-    return shard_map(inner, mesh=mesh, in_specs=(pspec, xspec),
-                     out_specs=(xspec, P()))(moe_params, x)
+    return jax.shard_map(inner, mesh=mesh, in_specs=(pspec, xspec),
+                         out_specs=(xspec, P()),
+                         check_vma=False)(moe_params, x)
 
 
 def constrain(x, dims):

@@ -8,8 +8,12 @@ Regenerate (only when the numerics are *intentionally* changed):
 
 tests/test_golden.py asserts the current engine (dense AND fused)
 reproduces these values, so kernel tuning can't silently drift numerics.
-The inputs are rebuilt from jax.random.PRNGKey (threefry — stable across
-jax versions and platforms by design), only outputs are stored.
+The inputs are rebuilt from jax.random.PRNGKey and only outputs are
+stored, so the fixtures hold for the installed jax only: what
+``jax.random.split``/``normal`` return changed when jax 0.5 turned
+``jax_threefry_partitionable`` on by default.  After such a change,
+regenerate; test_golden_fixtures_match_f64_oracle checks the new
+fixtures against the f64 oracle, independently of the engine.
 """
 import json
 import os

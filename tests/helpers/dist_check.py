@@ -59,8 +59,8 @@ def check_vjp_equivalence():
                  else D.make_allgather_ad_pair_loss(("data",)))
             loss, _ = f(e1n, e2n, lw1, lw2, tau, tau)
             return loss
-        fn = D.shard_map(inner, mesh=mesh, in_specs=(P("data"),) * 4,
-                         out_specs=P())
+        fn = jax.shard_map(inner, mesh=mesh, in_specs=(P("data"),) * 4,
+                           out_specs=P(), check_vma=False)
         return fn(e1, e2, lu1, lu2)
 
     ok = True
@@ -114,9 +114,9 @@ def check_fused_parity(K=4):
                     return loss
                 tspec = (P("data"),) * 2 if tau_is_arr else (P(), P())
                 targ = tau if tau_is_arr else jnp.zeros(())
-                fn = D.shard_map(inner, mesh=mesh,
-                                 in_specs=(P("data"),) * 4 + tspec,
-                                 out_specs=P())
+                fn = jax.shard_map(inner, mesh=mesh,
+                                   in_specs=(P("data"),) * 4 + tspec,
+                                   out_specs=P(), check_vma=False)
                 return fn(a, b, lu1, lu2, targ, targ)
 
             g = jax.grad(dist, argnums=(0, 1))(e1, e2)
@@ -162,9 +162,9 @@ def check_comm_reduction():
             return loss
 
         def outer(e1, e2, u1, u2):
-            return D.shard_map(inner, mesh=mesh,
-                               in_specs=(P("data"),) * 4,
-                               out_specs=P())(e1, e2, u1, u2)
+            return jax.shard_map(inner, mesh=mesh,
+                                 in_specs=(P("data"),) * 4, out_specs=P(),
+                                 check_vma=False)(e1, e2, u1, u2)
 
         def grad_fn(e1, e2, u1, u2):
             return jax.grad(lambda a, c: outer(a, c, u1, u2),

@@ -302,8 +302,8 @@ def check_prop():
                     "fsdp", axis=0, tiled=True), t)
             summed = jax.tree.map(lambda x: jax.lax.psum(x, ("fsdp",)), t)
             return scat, summed
-        fn = D.shard_map(inner, mesh=mesh, in_specs=(P(),),
-                         out_specs=(P(), P()))
+        fn = jax.shard_map(inner, mesh=mesh, in_specs=(P(),),
+                           out_specs=(P(), P()), check_vma=False)
         return fn(tree)
 
     leaf = st.lists(st.integers(min_value=-1000, max_value=1000),
@@ -342,8 +342,8 @@ def check_prop_hier():
             flat = jax.tree.map(
                 lambda x: jax.lax.psum(x, ("data", "fsdp")), t)
             return staged, flat
-        fn = D.shard_map(inner, mesh=mesh, in_specs=(P(),),
-                         out_specs=(P(), P()))
+        fn = jax.shard_map(inner, mesh=mesh, in_specs=(P(),),
+                           out_specs=(P(), P()), check_vma=False)
         return fn(tree)
 
     leaf = st.lists(st.integers(min_value=-1000, max_value=1000),

@@ -124,18 +124,19 @@ def main():
             def inner(e1l, e2l, lu1l, lu2l):
                 loss, _ = op(e1l, e2l, lu1l, lu2l, TAU, TAU, GAMMA)
                 return loss
-            return D.shard_map(inner, mesh=mesh,
-                               in_specs=(P("data"),) * 4,
-                               out_specs=P())(a, b, lu1, lu2)
+            return jax.shard_map(inner, mesh=mesh,
+                                 in_specs=(P("data"),) * 4, out_specs=P(),
+                                 check_vma=False)(a, b, lu1, lu2)
 
         def dist_sat(a, b):
             def inner(e1l, e2l, lu1l, lu2l):
                 _, (_, _, _, sat) = op(e1l, e2l, lu1l, lu2l, TAU, TAU,
                                        GAMMA)
                 return sat
-            return D.shard_map(inner, mesh=mesh,
-                               in_specs=(P("data"),) * 4,
-                               out_specs=P("data"))(a, b, lu1, lu2)
+            return jax.shard_map(inner, mesh=mesh,
+                                 in_specs=(P("data"),) * 4,
+                                 out_specs=P("data"),
+                                 check_vma=False)(a, b, lu1, lu2)
 
         grads = jax.grad(dist, argnums=(0, 1))(e1f, e2f)
         check(f"K=4 {impl}", grads, dist_sat(e1f, e2f))

@@ -149,7 +149,7 @@ def test_dg_dtau_is_derivative_of_estimator(tau):
 
 def _count_primitives(jaxpr, name):
     """Count ``name`` eqns in a jaxpr, recursing into sub-jaxprs."""
-    import jax.core as jc
+    import jax.extend.core as jc
     n = 0
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == name:
