@@ -28,6 +28,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro import tracing as TR
 from repro.configs.base import ArchConfig
 from repro.core import distributed as D
 from repro.core import fastclip as FC
@@ -268,88 +269,90 @@ def make_train_step(tc: TrainStepConfig):
         def loss_fn(params, tau_diff):
             e1, e2 = BB.encode_pair(params, tc.arch, batch, impl=tc.impl,
                                     precision=prec)
-            e1n = LS.l2_normalize(e1)
-            e2n = LS.l2_normalize(e2)
-            if fc.version == "openclip":
-                if tc.mesh_axes is None:
-                    loss = LS.mbcl_loss(e1n, e2n, tau_diff)
-                else:
-                    from jax.sharding import PartitionSpec as P
-                    axes = tuple(tc.mesh_axes)
-                    f = D.make_mbcl_loss(axes)
-                    loss = jax.shard_map(
-                        f, mesh=_current_mesh(),
-                        in_specs=(P(axes), P(axes), P()),
-                        out_specs=P(), check_vma=False)(e1n, e2n, tau_diff)
-                return loss, {"e1n": sg(e1n), "e2n": sg(e2n)}
-            t1 = fcs["tau1"] if fc.individual_tau else sg(tau_diff)
-            t2 = fcs["tau2"] if fc.individual_tau else sg(tau_diff)
-            loss, aux = loss_core(e1n, e2n, fcs["u1"], fcs["u2"], t1, t2,
-                                  idx, gamma)
-            aux["e1n"] = sg(e1n)
-            aux["e2n"] = sg(e2n)
-            return loss, aux
+            with jax.named_scope(TR.LOSS_OP):
+                e1n = LS.l2_normalize(e1)
+                e2n = LS.l2_normalize(e2)
+                if fc.version == "openclip":
+                    if tc.mesh_axes is None:
+                        loss = LS.mbcl_loss(e1n, e2n, tau_diff)
+                    else:
+                        from jax.sharding import PartitionSpec as P
+                        axes = tuple(tc.mesh_axes)
+                        f = D.make_mbcl_loss(axes)
+                        loss = jax.shard_map(
+                            f, mesh=_current_mesh(),
+                            in_specs=(P(axes), P(axes), P()),
+                            out_specs=P(), check_vma=False)(e1n, e2n, tau_diff)
+                    return loss, {"e1n": sg(e1n), "e2n": sg(e2n)}
+                t1 = fcs["tau1"] if fc.individual_tau else sg(tau_diff)
+                t2 = fcs["tau2"] if fc.individual_tau else sg(tau_diff)
+                loss, aux = loss_core(e1n, e2n, fcs["u1"], fcs["u2"], t1, t2,
+                                      idx, gamma)
+                aux["e1n"] = sg(e1n)
+                aux["e2n"] = sg(e2n)
+                return loss, aux
 
         (loss, aux), (grads, gtau) = jax.value_and_grad(
             loss_fn, argnums=(0, 1), has_aux=True)(
                 state["params"], tau1 if not fc.individual_tau else 0.0)
 
-        if tc.grad_clip:
-            grads, gnorm = clip_by_global_norm(grads, tc.grad_clip)
-        elif tc.guard:
-            gnorm = global_norm(grads)   # the guard's all-finite probe
-        else:
-            gnorm = jnp.asarray(0.0)
-
-        params, opt = tc.optimizer.update(
-            state["params"], grads, state["opt"], lr=lr, wd=tc.wd)
-
-        new_fc = dict(fcs)
-        metrics = {"loss": loss, "lr": lr, "gamma": gamma,
-                   "grad_norm": gnorm}
-        if fc.version == "openclip":
-            if fc.learnable_tau:
-                new_fc = FC.tau_update(fc, new_fc, gtau)
-            metrics["tau"] = new_fc.get("tau", tau1)
-        else:
-            new_fc["u1"] = aux["u1_new"]
-            new_fc["u2"] = aux["u2_new"]
-            stats_aux = {"lu1_new": aux["u1_rows"],
-                         "lu2_new": aux["u2_rows"],
-                         "m1": aux["stats"].m1, "m2": aux["stats"].m2,
-                         "dg1_dtau": aux["stats"].dg1_dtau,
-                         "dg2_dtau": aux["stats"].dg2_dtau}
-            t1r = tau1[idx] if fc.individual_tau else tau1
-            t2r = tau2[idx] if fc.individual_tau else tau2
-            tg = FC.tau_gradient(fc, stats_aux, t1r, t2r)
-            if fc.individual_tau:
-                new_fc = FC.tau_update(fc, new_fc, tg, idx=idx)
-                metrics["tau"] = jnp.mean(new_fc["tau1"])
-            elif tg is not None:
-                new_fc = FC.tau_update(fc, new_fc, tg)
-                metrics["tau"] = new_fc["tau"]
+        with jax.named_scope(TR.OPTIMIZER):
+            if tc.grad_clip:
+                grads, gnorm = clip_by_global_norm(grads, tc.grad_clip)
+            elif tc.guard:
+                gnorm = global_norm(grads)   # the guard's all-finite probe
             else:
-                metrics["tau"] = tau1
-            # u is log-domain; report a display-clamped linear mean
-            metrics["u_mean"] = jnp.mean(
-                jnp.exp(jnp.minimum(aux["u1_rows"], 80.0)))
-            # fraction of rows on which the last-resort EXP_CLAMP guard
-            # would fire (exact 0 <=> no pair clamps; ~0 under the LSE
-            # path on any healthy state)
-            metrics["sat_rate"] = jnp.mean(aux["sat"])
-            metrics["loss_value"] = FC.loss_value(
-                fc, {"lu1_new": aux["u1_rows"], "lu2_new": aux["u2_rows"]},
-                t1r, t2r)
-        new_fc["step"] = fcs["step"] + 1
+                gnorm = jnp.asarray(0.0)
 
-        new_state = {"params": params, "opt": opt, "fc": new_fc,
-                     "step": step + 1}
-        if tc.guard:
-            ok = RG.step_ok(loss, gnorm)
-            new_state = RG.select_state(ok, state, new_state)
-            metrics["skipped"] = 1.0 - ok.astype(jnp.float32)
-            metrics["nonfinite_rate"] = RG.grad_nonfinite_rate(grads)
-        return new_state, metrics
+            params, opt = tc.optimizer.update(
+                state["params"], grads, state["opt"], lr=lr, wd=tc.wd)
+
+            new_fc = dict(fcs)
+            metrics = {"loss": loss, "lr": lr, "gamma": gamma,
+                       "grad_norm": gnorm}
+            if fc.version == "openclip":
+                if fc.learnable_tau:
+                    new_fc = FC.tau_update(fc, new_fc, gtau)
+                metrics["tau"] = new_fc.get("tau", tau1)
+            else:
+                new_fc["u1"] = aux["u1_new"]
+                new_fc["u2"] = aux["u2_new"]
+                stats_aux = {"lu1_new": aux["u1_rows"],
+                             "lu2_new": aux["u2_rows"],
+                             "m1": aux["stats"].m1, "m2": aux["stats"].m2,
+                             "dg1_dtau": aux["stats"].dg1_dtau,
+                             "dg2_dtau": aux["stats"].dg2_dtau}
+                t1r = tau1[idx] if fc.individual_tau else tau1
+                t2r = tau2[idx] if fc.individual_tau else tau2
+                tg = FC.tau_gradient(fc, stats_aux, t1r, t2r)
+                if fc.individual_tau:
+                    new_fc = FC.tau_update(fc, new_fc, tg, idx=idx)
+                    metrics["tau"] = jnp.mean(new_fc["tau1"])
+                elif tg is not None:
+                    new_fc = FC.tau_update(fc, new_fc, tg)
+                    metrics["tau"] = new_fc["tau"]
+                else:
+                    metrics["tau"] = tau1
+                # u is log-domain; report a display-clamped linear mean
+                metrics["u_mean"] = jnp.mean(
+                    jnp.exp(jnp.minimum(aux["u1_rows"], 80.0)))
+                # fraction of rows on which the last-resort EXP_CLAMP guard
+                # would fire (exact 0 <=> no pair clamps; ~0 under the LSE
+                # path on any healthy state)
+                metrics["sat_rate"] = jnp.mean(aux["sat"])
+                metrics["loss_value"] = FC.loss_value(
+                    fc, {"lu1_new": aux["u1_rows"], "lu2_new": aux["u2_rows"]},
+                    t1r, t2r)
+            new_fc["step"] = fcs["step"] + 1
+
+            new_state = {"params": params, "opt": opt, "fc": new_fc,
+                         "step": step + 1}
+            if tc.guard:
+                ok = RG.step_ok(loss, gnorm)
+                new_state = RG.select_state(ok, state, new_state)
+                metrics["skipped"] = 1.0 - ok.astype(jnp.float32)
+                metrics["nonfinite_rate"] = RG.grad_nonfinite_rate(grads)
+            return new_state, metrics
 
     return train_step
 
@@ -497,21 +500,22 @@ def make_fsdp_train_step(tc: TrainStepConfig, param_dims=None):
 
         def loss_fn(p_shards, tau_diff):
             e1, e2 = encode_towers(p_shards)
-            e1n = LS.l2_normalize(e1)
-            e2n = LS.l2_normalize(e2)
-            if fc.version == "openclip":
-                local = mbcl(e1n, e2n, tau_diff)
-                return local, {"e1n": sg(e1n), "e2n": sg(e2n)}
-            t1in = fcs["tau1"] if fc.individual_tau else sg(tau_diff)
-            t2in = fcs["tau2"] if fc.individual_tau else sg(tau_diff)
-            local, u1n, u2n, lu1r, lu2r, stats, sat = _shard_fcco_inner(
-                shard_loss, axes, fc.individual_tau, e1n, e2n,
-                fcs["u1"], fcs["u2"], idx, t1in, t2in, gamma)
-            aux = {"u1_new": sg(u1n), "u2_new": sg(u2n),
-                   "u1_rows": sg(lu1r), "u2_rows": sg(lu2r),
-                   "stats": LS.RowStats(*jax.tree.map(sg, stats)),
-                   "sat": sg(sat), "e1n": sg(e1n), "e2n": sg(e2n)}
-            return local, aux
+            with jax.named_scope(TR.LOSS_OP):
+                e1n = LS.l2_normalize(e1)
+                e2n = LS.l2_normalize(e2)
+                if fc.version == "openclip":
+                    local = mbcl(e1n, e2n, tau_diff)
+                    return local, {"e1n": sg(e1n), "e2n": sg(e2n)}
+                t1in = fcs["tau1"] if fc.individual_tau else sg(tau_diff)
+                t2in = fcs["tau2"] if fc.individual_tau else sg(tau_diff)
+                local, u1n, u2n, lu1r, lu2r, stats, sat = _shard_fcco_inner(
+                    shard_loss, axes, fc.individual_tau, e1n, e2n,
+                    fcs["u1"], fcs["u2"], idx, t1in, t2in, gamma)
+                aux = {"u1_new": sg(u1n), "u2_new": sg(u2n),
+                       "u1_rows": sg(lu1r), "u2_rows": sg(lu2r),
+                       "stats": LS.RowStats(*jax.tree.map(sg, stats)),
+                       "sat": sg(sat), "e1n": sg(e1n), "e2n": sg(e2n)}
+                return local, aux
 
         if SH.inner_remat():
             loss_fn = jax.checkpoint(
@@ -525,68 +529,69 @@ def make_fsdp_train_step(tc: TrainStepConfig, param_dims=None):
         loss = SS.staged_psum(local)     # local is the /B contribution
         grads = SS.reduce_grads(grads, p_dims)
 
-        if tc.grad_clip:
-            grads, gnorm = clip_by_global_norm(
-                grads, tc.grad_clip, axes=("fsdp",), sharded_dims=p_dims)
-        elif tc.guard:
-            # axis-aware: psums sharded-leaf squares over fsdp, so every
-            # shard evaluates the identical guard predicate
-            gnorm = global_norm(grads, axes=("fsdp",), sharded_dims=p_dims)
-        else:
-            gnorm = jnp.asarray(0.0)
-
-        params, opt = tc.optimizer.update(
-            state["params"], grads, state["opt"], lr=lr, wd=tc.wd)
-
-        new_fc = dict(fcs)
-        metrics = {"loss": loss, "lr": lr, "gamma": gamma,
-                   "grad_norm": gnorm}
-        if fc.version == "openclip":
-            if fc.learnable_tau:
-                new_fc = FC.tau_update(fc, new_fc, SS.staged_psum(gtau))
-            metrics["tau"] = new_fc.get("tau", tau1)
-        else:
-            new_fc["u1"] = aux["u1_new"]
-            new_fc["u2"] = aux["u2_new"]
-            stats_aux = {"lu1_new": aux["u1_rows"],
-                         "lu2_new": aux["u2_rows"],
-                         "m1": aux["stats"].m1, "m2": aux["stats"].m2,
-                         "dg1_dtau": aux["stats"].dg1_dtau,
-                         "dg2_dtau": aux["stats"].dg2_dtau}
-            t1r = tau1[rel] if fc.individual_tau else tau1
-            t2r = tau2[rel] if fc.individual_tau else tau2
-            tg = FC.tau_gradient(fc, stats_aux, t1r, t2r)
-            if fc.individual_tau:
-                # per-row grads stay shard-local (stochastic coordinate
-                # update on the owned rows)
-                new_fc = FC.tau_update(fc, new_fc, tg, idx=rel)
-                metrics["tau"] = pmean(jnp.mean(new_fc["tau1"]))
-            elif tg is not None:
-                # scalar tau grads are batch means: pmean the equal-size
-                # shard means for the global mean
-                new_fc = FC.tau_update(fc, new_fc, pmean(tg))
-                metrics["tau"] = new_fc["tau"]
+        with jax.named_scope(TR.OPTIMIZER):
+            if tc.grad_clip:
+                grads, gnorm = clip_by_global_norm(
+                    grads, tc.grad_clip, axes=("fsdp",), sharded_dims=p_dims)
+            elif tc.guard:
+                # axis-aware: psums sharded-leaf squares over fsdp, so every
+                # shard evaluates the identical guard predicate
+                gnorm = global_norm(grads, axes=("fsdp",), sharded_dims=p_dims)
             else:
-                metrics["tau"] = tau1
-            metrics["u_mean"] = pmean(jnp.mean(
-                jnp.exp(jnp.minimum(aux["u1_rows"], 80.0))))
-            metrics["sat_rate"] = pmean(jnp.mean(aux["sat"]))
-            metrics["loss_value"] = pmean(FC.loss_value(
-                fc, {"lu1_new": aux["u1_rows"],
-                     "lu2_new": aux["u2_rows"]}, t1r, t2r))
-        new_fc["step"] = fcs["step"] + 1
+                gnorm = jnp.asarray(0.0)
 
-        new_state = {"params": params, "opt": opt, "fc": new_fc,
-                     "step": step + 1}
-        if tc.guard:
-            # loss/gnorm are already global (psum'd), so ok is identical
-            # on every shard and the local-shard selects stay consistent
-            ok = RG.step_ok(loss, gnorm)
-            new_state = RG.select_state(ok, state, new_state)
-            metrics["skipped"] = 1.0 - ok.astype(jnp.float32)
-            metrics["nonfinite_rate"] = pmean(
-                RG.grad_nonfinite_rate(grads))
-        return new_state, metrics
+            params, opt = tc.optimizer.update(
+                state["params"], grads, state["opt"], lr=lr, wd=tc.wd)
+
+            new_fc = dict(fcs)
+            metrics = {"loss": loss, "lr": lr, "gamma": gamma,
+                       "grad_norm": gnorm}
+            if fc.version == "openclip":
+                if fc.learnable_tau:
+                    new_fc = FC.tau_update(fc, new_fc, SS.staged_psum(gtau))
+                metrics["tau"] = new_fc.get("tau", tau1)
+            else:
+                new_fc["u1"] = aux["u1_new"]
+                new_fc["u2"] = aux["u2_new"]
+                stats_aux = {"lu1_new": aux["u1_rows"],
+                             "lu2_new": aux["u2_rows"],
+                             "m1": aux["stats"].m1, "m2": aux["stats"].m2,
+                             "dg1_dtau": aux["stats"].dg1_dtau,
+                             "dg2_dtau": aux["stats"].dg2_dtau}
+                t1r = tau1[rel] if fc.individual_tau else tau1
+                t2r = tau2[rel] if fc.individual_tau else tau2
+                tg = FC.tau_gradient(fc, stats_aux, t1r, t2r)
+                if fc.individual_tau:
+                    # per-row grads stay shard-local (stochastic coordinate
+                    # update on the owned rows)
+                    new_fc = FC.tau_update(fc, new_fc, tg, idx=rel)
+                    metrics["tau"] = pmean(jnp.mean(new_fc["tau1"]))
+                elif tg is not None:
+                    # scalar tau grads are batch means: pmean the equal-size
+                    # shard means for the global mean
+                    new_fc = FC.tau_update(fc, new_fc, pmean(tg))
+                    metrics["tau"] = new_fc["tau"]
+                else:
+                    metrics["tau"] = tau1
+                metrics["u_mean"] = pmean(jnp.mean(
+                    jnp.exp(jnp.minimum(aux["u1_rows"], 80.0))))
+                metrics["sat_rate"] = pmean(jnp.mean(aux["sat"]))
+                metrics["loss_value"] = pmean(FC.loss_value(
+                    fc, {"lu1_new": aux["u1_rows"],
+                         "lu2_new": aux["u2_rows"]}, t1r, t2r))
+            new_fc["step"] = fcs["step"] + 1
+
+            new_state = {"params": params, "opt": opt, "fc": new_fc,
+                         "step": step + 1}
+            if tc.guard:
+                # loss/gnorm are already global (psum'd), so ok is identical
+                # on every shard and the local-shard selects stay consistent
+                ok = RG.step_ok(loss, gnorm)
+                new_state = RG.select_state(ok, state, new_state)
+                metrics["skipped"] = 1.0 - ok.astype(jnp.float32)
+                metrics["nonfinite_rate"] = pmean(
+                    RG.grad_nonfinite_rate(grads))
+            return new_state, metrics
 
     def train_step(state, batch, idx):
         b_specs = SS.batch_specs(batch)

@@ -1,4 +1,6 @@
-from repro.data.pipeline import DevicePrefetcher, ShardedLoader  # noqa: F401
+from repro.data.pipeline import (  # noqa: F401
+    DevicePrefetcher, InputWaits, ShardedLoader,
+)
 from repro.data.streaming import (  # noqa: F401
     StreamingDataset, StreamingLoader, write_contrastive_shards,
     write_shards,

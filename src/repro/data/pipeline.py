@@ -9,15 +9,20 @@ only ever draws indices it owns, so u updates are shard-local (paper §3
 producer thread that assembles host batches and issues the host->device
 transfer ``depth`` steps ahead, so H2D copy (and the numpy batch gather)
 overlaps the previous step's compute instead of serializing with it.
+It writes the input path's ``repro.tracing`` spans and counts, in
+``InputWaits``, how often and how long the consumer waited for a batch.
 """
 from __future__ import annotations
 
 import dataclasses
 import queue
 import threading
+import time
 from typing import Callable, Iterator, Optional, Tuple
 
 import numpy as np
+
+from repro import tracing as TR
 
 
 @dataclasses.dataclass
@@ -148,6 +153,20 @@ class ShardedLoader:
 _STOP = object()
 
 
+@dataclasses.dataclass
+class InputWaits:
+    """What the consumer of a ``DevicePrefetcher`` waited for: batches
+    asked for, asks that found the queue empty, and seconds spent
+    blocked.  Empty at most asks means the run is input-bound."""
+    asks: int = 0
+    empty: int = 0
+    waited_s: float = 0.0
+
+    def __str__(self):
+        return (f"input: queue empty at {self.empty} of {self.asks} asks, "
+                f"waited {self.waited_s:.1f} s")
+
+
 class DevicePrefetcher:
     """Double-buffered host->device prefetch over any finite iterator.
 
@@ -158,13 +177,20 @@ class DevicePrefetcher:
     transferred: with ``depth=2`` the copy of step t+1 runs while step t
     computes.  Producer exceptions are re-raised on the consumer side at
     the position they occurred.  Iteration order is exactly the wrapped
-    iterator's."""
+    iterator's.
+
+    The producer's ``next`` runs in a ``repro.input.make`` span and its
+    ``transform`` in ``repro.input.copy``; the consumer's wait runs in
+    ``repro.input.wait`` and adds to ``waits`` (pass one ``InputWaits`` to
+    several prefetchers to count them together)."""
 
     def __init__(self, iterator: Iterator, depth: int = 2,
-                 transform: Optional[Callable] = None):
+                 transform: Optional[Callable] = None,
+                 waits: Optional[InputWaits] = None):
         assert depth >= 1
         self._q: "queue.Queue" = queue.Queue(maxsize=depth)
         self._transform = transform
+        self.waits = waits if waits is not None else InputWaits()
         self._stop = threading.Event()   # set by close(): unblocks producer
         self._done = False               # latched on _STOP: repeated next()
         #                                  keeps raising StopIteration
@@ -182,9 +208,16 @@ class DevicePrefetcher:
 
         def produce():
             try:
-                for item in iterator:
-                    if not put(self._transform(item)
-                               if self._transform else item):
+                items = iter(iterator)
+                while True:
+                    with TR.span(TR.INPUT_MAKE):
+                        item = next(items, _STOP)
+                    if item is _STOP:
+                        break
+                    if self._transform:
+                        with TR.span(TR.INPUT_COPY):
+                            item = self._transform(item)
+                    if not put(item):
                         return
             except BaseException as e:  # surfaced on the consumer thread
                 if not put(e):
@@ -211,10 +244,17 @@ class DevicePrefetcher:
     def __next__(self):
         if self._done:
             raise StopIteration
-        item = self._q.get()
+        empty = self._q.empty()
+        t = time.perf_counter()
+        with TR.span(TR.INPUT_WAIT):
+            item = self._q.get()
+        waited = time.perf_counter() - t
         if item is _STOP:
             self._done = True
             raise StopIteration
         if isinstance(item, BaseException):
             raise item
+        self.waits.asks += 1
+        self.waits.empty += empty
+        self.waits.waited_s += waited
         return item

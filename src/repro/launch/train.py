@@ -116,6 +116,13 @@ Streaming data + curricula (PR 7, ``repro.data.streaming`` /
       their positional tables (pooled patch grid, sliced text prefix).
       Scheduled values must divide the native sizes; each stage is one
       extra jit compile.
+
+With ``--prefetch`` > 0 the launcher ends (also after a SIGTERM drain)
+with ``input: queue empty at E of A asks, waited S s``: of the A batches
+the loop asked for, E found the prefetch queue empty, and the loop spent
+S seconds blocked on it.  E close to A means the run is input-bound.
+The input path's profiler spans and the towers', loss op's and
+optimizer's scopes are listed in ``repro.tracing``.
 """
 from __future__ import annotations
 
@@ -135,8 +142,8 @@ from repro.core import fastclip as FC
 from repro.core import shard_state as SS
 from repro.core import train_step as TS
 from repro.core.schedules import lr_warmup_cosine
-from repro.data import (ContrastiveDataset, DevicePrefetcher, LMDataset,
-                        PairedEmbeddingDataset, ShardedLoader,
+from repro.data import (ContrastiveDataset, DevicePrefetcher, InputWaits,
+                        LMDataset, PairedEmbeddingDataset, ShardedLoader,
                         StreamingDataset, StreamingLoader)
 from repro.data import curriculum as CU
 from repro.launch import multiprocess as MP
@@ -497,11 +504,14 @@ def main(argv=None):
                                         context_sched)
             yield epoch, step, idx, batch
 
+    # every stream's waits for a batch, for the ``input:`` line at the end
+    waits = InputWaits()
+
     def make_stream(from_step):
         it = host_stream(from_step)
         if args.prefetch > 0:
             return DevicePrefetcher(it, depth=args.prefetch,
-                                    transform=to_device)
+                                    transform=to_device, waits=waits)
         return map(to_device, it)
 
     def close_stream(s):
@@ -626,6 +636,8 @@ def main(argv=None):
             CK.set_fault_hook(None)
         for s, h in prev_handlers.items():
             signal.signal(s, h)
+    if args.prefetch > 0:
+        print(waits, flush=True)
 
     if preempted:
         # preemption contract: final synchronous checkpoint, clean

@@ -10,12 +10,17 @@ Both towers take ``impl`` (attention implementation: "chunked"/"flash"/
 with ``bf16`` the tower matmuls/activations run in bf16 while params stay
 f32 masters and the embeddings are cast back to f32 at the tower exit —
 the loss layer (l2_normalize + the exact LSE engine) is always f32.
+
+Each tower runs under its ``repro.tracing`` scope (``image_tower``,
+``text_tower``), so every caller's HLO (train step, eval, serving)
+names its operations by tower.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro import tracing as TR
 from repro.configs.base import ArchConfig
 from repro.models import layers as L
 from repro.models import precision as PR
@@ -47,11 +52,13 @@ def init_clip(rng, cfg: ArchConfig):
 def encode_image(params, cfg: ArchConfig, images, *, impl="chunked",
                  precision=PR.F32):
     c = cfg.clip
-    if c.vision_arch == "vit":
-        return V.apply_vit(params["vision"], c, images, impl=impl,
-                           precision=precision)
-    # ResNet has no attention; impl is a no-op for it by design.
-    return R.apply_resnet(params["vision"], c, images, precision=precision)
+    with jax.named_scope(TR.IMAGE_TOWER):
+        if c.vision_arch == "vit":
+            return V.apply_vit(params["vision"], c, images, impl=impl,
+                               precision=precision)
+        # ResNet has no attention; impl is a no-op for it by design.
+        return R.apply_resnet(params["vision"], c, images,
+                              precision=precision)
 
 
 def encode_text(params, cfg: ArchConfig, tokens, *, impl="chunked",
@@ -59,16 +66,18 @@ def encode_text(params, cfg: ArchConfig, tokens, *, impl="chunked",
     """tokens: (B, S) int32 with S <= context_length; shorter inputs
     (token-length curriculum, repro.data.curriculum) use the positional-
     embedding prefix."""
-    x = L.embed_tokens(params["tok_embed"], tokens,
-                       dtype=precision.compute_dtype)
-    x = x + params["pos_embed"][:, :x.shape[1]].astype(x.dtype)
-    x = T.apply_stack(params["text_blocks"], cfg, x, mlp="gelu", impl=impl,
-                      precision=precision)
-    x = L.rmsnorm(params["text_norm"], x)
-    pooled = x[:, -1]  # last token (synthetic data: fixed-length captions)
-    out = jnp.einsum("bd,de->be", pooled,
-                     params["text_proj"].astype(x.dtype))
-    return PR.cast_output(precision, out)
+    with jax.named_scope(TR.TEXT_TOWER):
+        x = L.embed_tokens(params["tok_embed"], tokens,
+                           dtype=precision.compute_dtype)
+        x = x + params["pos_embed"][:, :x.shape[1]].astype(x.dtype)
+        x = T.apply_stack(params["text_blocks"], cfg, x, mlp="gelu",
+                          impl=impl, precision=precision)
+        x = L.rmsnorm(params["text_norm"], x)
+        # last token (synthetic data: fixed-length captions)
+        pooled = x[:, -1]
+        out = jnp.einsum("bd,de->be", pooled,
+                         params["text_proj"].astype(x.dtype))
+        return PR.cast_output(precision, out)
 
 
 def encode_pair(params, cfg: ArchConfig, batch, *, impl="chunked",

@@ -27,6 +27,9 @@ Checks:
           (microbatch=1) run within 5e-5 on loss/params/log-u over 3
           steps, with bit-identical counters/taus where the math is
           exact.
+  scopes <file>  writes the compiled sharded step on a data:1,fsdp:2 mesh
+          as HLO text to <file> (tests/test_tracing.py reads its
+          ``repro.tracing`` scopes).
   hlo_microbatch  the lowered microbatch=2 step carries MORE
           reduce-scatters than the unpipelined step (one per micro-step
           per sharded leaf — the overlappable collectives) while the
@@ -469,6 +472,22 @@ def check_launch():
     return ok
 
 
+def check_scopes(out_path):
+    cfg, fc, tckw, batches = _setup()
+    mesh = SS.make_train_mesh(1, 2)
+    TS.set_mesh(mesh)
+    tc = TS.TrainStepConfig(**tckw, mesh_axes=SS.TRAIN_AXES, fsdp=True)
+    state0 = TS.init_train_state(jax.random.PRNGKey(1), tc)
+    st, _ = SS.shard_train_state(state0, mesh)
+    idx, batch = batches[0]
+    hlo = donated_jit(TS.make_train_step(tc)).lower(
+        st, batch, idx).compile().as_text()
+    with open(out_path, "w") as f:
+        f.write(hlo)
+    print("PASS")
+    return True
+
+
 CHECKS = {
     "parity": check_parity,
     "parity_v2": lambda: check_parity("v2"),
@@ -480,7 +499,8 @@ CHECKS = {
     "microbatch": check_microbatch,
     "hlo_microbatch": check_hlo_microbatch,
     "launch": check_launch,
+    "scopes": check_scopes,
 }
 
 if __name__ == "__main__":
-    sys.exit(0 if CHECKS[sys.argv[1]]() else 1)
+    sys.exit(0 if CHECKS[sys.argv[1]](*sys.argv[2:]) else 1)
