@@ -1,5 +1,5 @@
 from repro.data.pipeline import (  # noqa: F401
-    DevicePrefetcher, InputWaits, ShardedLoader,
+    DevicePrefetcher, InputWaits, ShardedLoader, device_array,
 )
 from repro.data.streaming import (  # noqa: F401
     StreamingDataset, StreamingLoader, write_contrastive_shards,

@@ -15,6 +15,7 @@ It writes the input path's ``repro.tracing`` spans and counts, in
 from __future__ import annotations
 
 import dataclasses
+import functools
 import queue
 import threading
 import time
@@ -151,6 +152,40 @@ class ShardedLoader:
 # ---------------------------------------------------------------------------
 
 _STOP = object()
+
+
+def unflatten(flat, shape):
+    """``flat.reshape(shape)`` in jnp ops for the device.  An image batch
+    ``(b, h, w, c)`` goes through ``(b, h, w * c)`` and ``(h, w * c, b)``:
+    a TPU lays the batch out with its batch dimension minor, and a direct
+    reshape first lays out a ``(b, h, w, c)`` intermediate with ``c``
+    minor, padded to a whole tile (42x the batch's bytes at c = 3, more
+    than a v5e has beside a model)."""
+    import jax.numpy as jnp
+    if len(shape) != 4:
+        return flat.reshape(shape)
+    b, h, w, c = shape
+    x = flat.reshape(b, h, w * c).transpose(1, 2, 0)
+    return jnp.moveaxis(x.reshape(h, w, c, b), -1, 0)
+
+
+@functools.cache
+def _unflatten_jit():
+    import jax
+    return jax.jit(unflatten, static_argnums=1)
+
+
+def device_array(x):
+    """``jnp.asarray(x)`` for a host array, copied flat and reshaped on
+    the device by ``unflatten``.
+
+    A copy in the array's own shape is transposed on the host into the
+    device's layout by the runtime's threads: for an image batch, with
+    the batch dimension minor.  A flat array's device layout is its host
+    order, so its copy is a plain one, and the device does the relayout."""
+    import jax.numpy as jnp
+    x = np.asarray(x)
+    return _unflatten_jit()(jnp.asarray(x.reshape(-1)), x.shape)
 
 
 @dataclasses.dataclass

@@ -53,16 +53,32 @@ class ContrastiveDataset:
                                     size=(self.n_classes, 4))
         self._img_key = R.stream_key(self.seed, self.IMAGE_STREAM)
 
+    def _proto_blocks(self, idx):
+        """(b, 8, 1, 8, 1, 3): each sample's 8x8x3 prototype, one value
+        for each (up, up) block of its image, up = image_size // 8."""
+        return self.protos[self.classes[idx]][:, :, None, :, None, :]
+
     def clean_images(self, idx):
         """The noise-free rendered prototypes (what the shard writer
         materializes; the noise augment is re-applied at decode)."""
-        base = self.protos[self.classes[idx]]             # (b, 8, 8, 3)
-        return np.repeat(np.repeat(base, self.image_size // 8, axis=1),
-                         self.image_size // 8, axis=2)
+        blocks = self._proto_blocks(np.asarray(idx).reshape(-1))
+        b, up = len(blocks), self.image_size // 8
+        return np.broadcast_to(blocks, (b, 8, up, 8, up, 3)).reshape(
+            b, 8 * up, 8 * up, 3)
 
     def images(self, idx):
-        return R.add_gaussian_noise(self.clean_images(idx), self.noise,
-                                    self._img_key, idx)
+        """``add_gaussian_noise(clean_images(idx), ...)``, bit for bit,
+        without rendering the clean images: the prototypes are added into
+        the noise by a broadcast over the upsampling blocks."""
+        idx = np.asarray(idx).reshape(-1)
+        up = self.image_size // 8
+        protos = self._proto_blocks(idx)
+
+        def add_protos(lo, hi, block):
+            blocks = block.reshape(hi - lo, 8, up, 8, up, 3)
+            blocks += protos[lo:hi]
+        return R.noise_plus(self.noise, self._img_key, idx,
+                            (8 * up, 8 * up, 3), add_protos)
 
     def texts(self, idx):
         b = len(idx)

@@ -121,6 +121,10 @@ With ``--prefetch`` > 0 the launcher ends (also after a SIGTERM drain)
 with ``input: queue empty at E of A asks, waited S s``: of the A batches
 the loop asked for, E found the prefetch queue empty, and the loop spent
 S seconds blocked on it.  E close to A means the run is input-bound.
+A line of its own follows, ``noise: P of F fills on W threads``: of the
+F per-sample noise fills that made this run's batches, P split their
+rows over the data layer's W-thread pool (``repro.data.rng``); the
+others were too small to gain from it.
 The input path's profiler spans and the towers', loss op's and
 optimizer's scopes are listed in ``repro.tracing``.
 """
@@ -144,8 +148,9 @@ from repro.core import train_step as TS
 from repro.core.schedules import lr_warmup_cosine
 from repro.data import (ContrastiveDataset, DevicePrefetcher, InputWaits,
                         LMDataset, PairedEmbeddingDataset, ShardedLoader,
-                        StreamingDataset, StreamingLoader)
+                        StreamingDataset, StreamingLoader, device_array)
 from repro.data import curriculum as CU
+from repro.data import rng as DR
 from repro.launch import multiprocess as MP
 from repro.launch.compile_cache import init_compile_cache
 from repro.launch.steps import donated_jit
@@ -490,9 +495,9 @@ def main(argv=None):
                     (len(idx_np),) + v.shape[1:])
                 for k, v in batch.items()}
             return epoch, step, idx_dev, dev_batch
-        # jnp.asarray dispatches the async H2D copy on the producer thread
+        # the async H2D copies are dispatched on the producer thread
         return (epoch, step, jnp.asarray(idx),
-                {k: jnp.asarray(v) for k, v in batch.items()})
+                {k: device_array(v) for k, v in batch.items()})
 
     def host_stream(from_step):
         for epoch, step, idx, batch in loader.steps(args.steps,
@@ -570,6 +575,7 @@ def main(argv=None):
     first = True
     done = start
     preempted = False
+    fills0 = DR.fills()
     stream = make_stream(start)
     try:
         running = True
@@ -638,6 +644,7 @@ def main(argv=None):
             signal.signal(s, h)
     if args.prefetch > 0:
         print(waits, flush=True)
+    print(DR.fills().since(fills0), flush=True)
 
     if preempted:
         # preemption contract: final synchronous checkpoint, clean

@@ -5,7 +5,8 @@ kernel is lowered and compiled at ViT-B/32 widths for a described v5e
 chip.  This catches what interpret mode cannot: a block layout or tiling
 the chip's compiler refuses (the FCCO kernels' 1-D vector blocks were
 refused this way), or too much VMEM.  Nothing runs, so these tests say
-nothing about results or times.
+nothing about results or times.  One more check reads the device layouts
+the compiler gives the launcher's batches: why they are copied flat.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -16,6 +17,7 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
+from repro.data.pipeline import unflatten
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gcl_loss import gcl_pair_grads, gcl_pair_stats
 
@@ -138,3 +140,31 @@ def test_bf16_kernels_compile_under_highest_precision(
                 lambda q, k, v: flash_attention(q, k, v, causal=False),
                 x, x, x)
     assert "tpu_custom_call" in text
+
+
+
+def test_flat_batch_copy_keeps_host_order(one_chip, no_persistent_cache):
+    """The launcher copies each batch flat (``repro.data.device_array``)
+    and the device lays it out with ``unflatten``.  The image batch's own
+    layout has the batch dimension minor, which a copy in that shape is
+    transposed into on the host; the flat array keeps its host order
+    (1-D tiles).  The relayout ends in the batch's own layout and holds
+    at most about twice the batch beside it: a direct reshape laid out a
+    (b, h, w, c) intermediate with c = 3 padded to 128 (42x)."""
+    shape = (b, 224, 224, 3)
+    flat = jax.ShapeDtypeStruct((b * 224 * 224 * 3,), jnp.float32,
+                                sharding=one_chip)
+
+    def layout(x):
+        return jax.jit(lambda a: a + 1).lower(x).compile() \
+            .input_formats[0][0].layout
+    images = layout(jax.ShapeDtypeStruct(shape, jnp.float32,
+                                         sharding=one_chip))
+    assert images.major_to_minor[-1] == 0           # the batch is minor
+    assert layout(flat).major_to_minor == (0,)
+    assert all(len(tile) == 1 for tile in layout(flat).tiling)
+    relayout = jax.jit(unflatten, static_argnums=1).lower(flat, shape) \
+        .compile()
+    assert relayout.output_formats.layout == images
+    mem = relayout.memory_analysis()
+    assert mem.temp_size_in_bytes <= 3 * mem.output_size_in_bytes
